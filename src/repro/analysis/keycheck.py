@@ -110,18 +110,15 @@ def _is_key_aval(aval) -> bool:
 
 
 def _src(eqn) -> tuple:
-    try:
-        from jax._src import source_info_util
-        fr = source_info_util.user_frame(eqn.source_info)
-        if fr is not None:
-            return fr.file_name, fr.start_line
-    except Exception:
-        pass
+    from jax._src import source_info_util
+    fr = source_info_util.user_frame(eqn.source_info.traceback)
+    if fr is not None:
+        return fr.file_name, fr.start_line
     return "<unknown>", 0
 
 
 class _Walker:
-    """Key-identity dataflow over a ClosedJaxpr (recursing into pjit,
+    """Key-identity dataflow over a ClosedJaxpr (recursing into jit,
     scan, while, cond and custom-call sub-jaxprs)."""
 
     def __init__(self):
@@ -219,7 +216,7 @@ class _Walker:
             self._fresh_outs(env, eqn, depth, "fold-child")
         elif name == "random_bits":
             self._record(env, eqn.invars[0], SAMPLE, eqn, ctx, depth)
-        elif name == "pjit":
+        elif name == "jit":
             sub = eqn.params["jaxpr"]
             sub_env = self._enter(sub.jaxpr, eqn.invars, sub.consts, env,
                                   ctx, depth)

@@ -150,6 +150,9 @@ def run(root: Optional[Path] = None, devices: int = 4,
     from repro.analysis.lint import repo_root
     root = root or repo_root()
     env = dict(os.environ)
+    # the child analyses fake CPU devices; pinning the platform keeps it
+    # off an accelerator the parent process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     src = str(root / "src")
     env["PYTHONPATH"] = src + (

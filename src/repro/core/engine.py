@@ -321,6 +321,35 @@ def seed_batch_loop(env, cfg, T: int, n_seeds: int, algo="decbyzpg"):
     return compiled(key, build)
 
 
+def lane_jit(batched, sharding, n_args: int, replicated=()):
+    """Jit a row-batched program over the flattened lane×seed batch.
+
+    ``sharding`` is the caller's :func:`lane_sharding` of the rows.
+    Without one this is a plain ``jax.jit``. Otherwise every device of
+    its mesh runs ``batched`` on its own rows under ``shard_map``: rows
+    are independent, and the Pallas kernels inside cannot be partitioned
+    automatically. Argument indices in ``replicated`` are passed whole to
+    every device. Outputs stay row-sharded on a local mesh and come back
+    replicated on a process-spanning one, so every host can read whole
+    histories."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as _P
+
+    from repro.distributed.sharding import spans_processes
+    if sharding is None:
+        return jax.jit(batched)
+    mesh = sharding.mesh
+    specs = tuple(_P() if i in replicated else _P("lane")
+                  for i in range(n_args))
+    local = jax.shard_map(batched, mesh=mesh, in_specs=specs,
+                          out_specs=_P("lane"), check_vma=False)
+    out = NamedSharding(mesh, _P()) if spans_processes(mesh) else sharding
+    return jax.jit(local,
+                   in_shardings=tuple(NamedSharding(mesh, sp)
+                                      for sp in specs),
+                   out_shardings=out)
+
+
 def lane_batch_loop(env, static_cfg, T: int, traced_names, n_rows: int,
                     algo="decbyzpg"):
     """Compiled flattened lane×seed batch: ``(vals (R, n_traced), seeds
@@ -334,8 +363,7 @@ def lane_batch_loop(env, static_cfg, T: int, traced_names, n_rows: int,
     batch axis is sharded over the ``lane_mesh`` when the row count
     divides the device count (single device: identity layout).
     """
-    from repro.distributed.sharding import (lane_mesh, lane_out_sharding,
-                                            lane_sharding)
+    from repro.distributed.sharding import lane_mesh, lane_sharding
     algo = Spec.of(algo)
     a = _algo(algo)
     names = tuple(traced_names)
@@ -355,11 +383,7 @@ def lane_batch_loop(env, static_cfg, T: int, traced_names, n_rows: int,
             carry = a.init_carry(env, static_cfg, ks.init)
             return loop(*carry, jax.random.split(ks.loop, T), ks.coin)
 
-        batched = jax.vmap(one)
-        if sharding is None:
-            return jax.jit(batched)
-        return jax.jit(batched, in_shardings=(sharding, sharding),
-                       out_shardings=lane_out_sharding(mesh, n_rows))
+        return lane_jit(jax.vmap(one), sharding, 2)
 
     return compiled(key, build)
 
@@ -391,8 +415,7 @@ def lane_init_loop(env, static_cfg, n_rows: int, algo="decbyzpg"):
     key, vmapped over the flattened lane×seed batch.  Row r's carry is
     exactly what :func:`lane_batch_loop` builds internally for the same
     seed — the entry point of the windowed execution path."""
-    from repro.distributed.sharding import (lane_mesh, lane_out_sharding,
-                                            lane_sharding)
+    from repro.distributed.sharding import lane_mesh, lane_sharding
     algo = Spec.of(algo)
     a = _algo(algo)
     mesh = lane_mesh()
@@ -405,11 +428,7 @@ def lane_init_loop(env, static_cfg, n_rows: int, algo="decbyzpg"):
         def one(seed):
             return a.init_carry(env, static_cfg, seed_keys(seed).init)
 
-        batched = jax.vmap(one)
-        if sharding is None:
-            return jax.jit(batched)
-        return jax.jit(batched, in_shardings=(sharding,),
-                       out_shardings=lane_out_sharding(mesh, n_rows))
+        return lane_jit(jax.vmap(one), sharding, 1)
 
     return compiled(key, build)
 
@@ -427,8 +446,7 @@ def lane_window_loop(env, static_cfg, T: int, traced_names, W: int,
     exact key stream of the uninterrupted :func:`lane_batch_loop` scan —
     bit for bit (``T`` stays in the key because the stream length is
     baked into the split)."""
-    from repro.distributed.sharding import (lane_mesh, lane_out_sharding,
-                                            lane_sharding)
+    from repro.distributed.sharding import lane_mesh, lane_sharding
     algo = Spec.of(algo)
     a = _algo(algo)
     if a.build_window is None:
@@ -451,16 +469,8 @@ def lane_window_loop(env, static_cfg, T: int, traced_names, W: int,
             step_keys = jax.random.split(ks.loop, T)[ts]
             return window(carry, ts, step_keys, ks.coin)
 
-        batched = jax.vmap(one, in_axes=(0, 0, 0, None))
-        if sharding is None:
-            return jax.jit(batched)
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as _P
-        repl = NamedSharding(mesh, _P())
-        out = lane_out_sharding(mesh, n_rows)
-        return jax.jit(batched,
-                       in_shardings=(sharding, sharding, sharding, repl),
-                       out_shardings=(out, out))
+        return lane_jit(jax.vmap(one, in_axes=(0, 0, 0, None)), sharding,
+                        4, replicated=(3,))
 
     return compiled(key, build)
 

@@ -247,13 +247,22 @@ def init_flat_fed_state(cfg: ModelConfig, fed: FedConfig, K: int, key,
     """
     from jax.flatten_util import ravel_pytree
     vec0, unravel = ravel_pytree(init_params(cfg, key, dtype))
-    theta = jnp.tile(vec0, (K, 1))
-    if mesh is not None and mesh.shape.get("model", 1) > 1:
-        theta = jax.device_put(theta, flat_param_sharding(mesh))
     opt = get_optimizer(fed.optimizer, fed.lr, maximize=False)
-    return FlatFedState(theta, jnp.array(theta), jnp.zeros_like(theta),
-                        jax.vmap(opt.init)(theta),
-                        jnp.zeros((), jnp.int32)), unravel
+
+    def build(vec0):
+        theta = jnp.tile(vec0, (K, 1))
+        return FlatFedState(theta, jnp.array(theta), jnp.zeros_like(theta),
+                            jax.vmap(opt.init)(theta),
+                            jnp.zeros((), jnp.int32))
+
+    if mesh is not None and mesh.shape.get("model", 1) > 1:
+        # built in place: eagerly, the stacks would first exist whole on
+        # one device (a vmapped optimizer init does not follow theta's
+        # sharding)
+        shardings = flat_fed_state_shardings(mesh,
+                                             jax.eval_shape(build, vec0))
+        return jax.jit(build, out_shardings=shardings)(vec0), unravel
+    return build(vec0), unravel
 
 
 def flat_fed_state_shardings(mesh, state_shape: FlatFedState):

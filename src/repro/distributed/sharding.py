@@ -89,28 +89,20 @@ def spans_processes(mesh: Optional[Mesh]) -> bool:
     return len({d.process_index for d in mesh.devices.flat}) > 1
 
 
-def lane_out_sharding(mesh: Optional[Mesh], n_rows: int) \
-        -> Optional[NamedSharding]:
-    """Output sharding for lane-batched programs: row-sharded like the
-    inputs on a local mesh, but **fully replicated** on a
-    process-spanning mesh so every host can pull complete histories for
-    summaries/checkpoints (a cross-process row-sharded output would be
-    only partially addressable on each host)."""
-    s = lane_sharding(mesh, n_rows)
-    if s is not None and spans_processes(mesh):
-        return NamedSharding(mesh, P())
-    return s
-
-
 def padded_rows(mesh: Optional[Mesh], n_rows: int) -> int:
-    """Smallest multiple of the lane-mesh device count ≥ ``n_rows``
-    (``n_rows`` itself without a mesh).  The engine pads the flattened
-    lane×seed batch to this size with duplicate rows — sliced off before
-    summaries — so uneven batches shard over the mesh instead of falling
-    back to the identity layout."""
-    if mesh is None or n_rows % mesh.size == 0:
+    """Rows the engine runs for a flattened lane×seed batch of
+    ``n_rows``: ``n_rows`` itself without a mesh; on a lane mesh the
+    smallest multiple of the device count ≥ ``n_rows`` that gives every
+    device at least two rows.  The engine pads the batch to this size
+    with duplicate rows — sliced off before summaries — so uneven batches
+    shard over the mesh instead of falling back to the identity layout.
+    At least two, because a device holding a single row runs the program
+    with a unit batch axis, which XLA compiles to different arithmetic:
+    its rows would then differ in the last bits from the same rows run
+    together on one device."""
+    if mesh is None:
         return n_rows
-    return ((n_rows + mesh.size - 1) // mesh.size) * mesh.size
+    return max(2, -(-n_rows // mesh.size)) * mesh.size
 
 
 def init_distributed(coordinator_address: str, num_processes: int,
@@ -125,10 +117,7 @@ def init_distributed(coordinator_address: str, num_processes: int,
     No-op for a single process."""
     if num_processes <= 1:
         return
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass    # older jaxlib without the option: GPU/TPU transports only
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)

@@ -14,11 +14,7 @@ Backend selection, most specific wins:
 1. per-call ``backend=`` keyword,
 2. the process-global override (:func:`set_backend` / :func:`use_backend`),
 3. the ``REPRO_KERNEL_BACKEND`` environment variable,
-4. auto: ``"pallas"`` on TPU, ``"jnp"`` elsewhere — with a per-kernel
-   size-threshold fallback: below ``auto_jnp_below`` operand elements
-   (declared at registration, calibrated from BENCH_kernels.json) the
-   launch/interpret overhead dominates and auto picks ``"jnp"`` even on
-   TPU. Explicit overrides (1-3) are never second-guessed.
+4. auto: ``"pallas"`` on TPU at every operand size, ``"jnp"`` elsewhere.
 
 The d-tiled kernels accept a ``block_d=`` slab width (the VMEM tile along
 the parameter dimension). It is a Pallas tiling knob only, so the
@@ -51,11 +47,10 @@ _GLOBAL_BACKEND: Optional[str] = None
 _KERNELS: Dict[str, "Kernel"] = {}
 
 #: backend-selection tally keyed ``(kernel_name, backend, reason)`` with
-#: reason in {"call", "global", "env", "auto", "auto_jnp_below"} — one
-#: increment per trace-time dispatch decision, so a silent
-#: ``auto_jnp_below`` fallback shows up here (and in the obs manifest)
-#: instead of only as a 2x bench miss. Always on: selection happens at
-#: trace time, never inside a compiled program.
+#: reason in {"call", "global", "env", "auto"} — one increment per
+#: trace-time dispatch decision, so which backend a traced program baked
+#: in shows up here (and in the obs manifest). Always on: selection
+#: happens at trace time, never inside a compiled program.
 _DISPATCH_COUNTS: collections.Counter = collections.Counter()
 
 
@@ -118,21 +113,14 @@ class Kernel:
     through unchanged — except the Pallas tiling knob ``block_d``, which
     is dropped for the oracle — so a Kernel is call-compatible with its
     oracle plus optional ``backend=`` / ``block_d=`` keywords.
-
-    ``auto_jnp_below`` (element count of the first operand) is the
-    auto-mode fallback threshold: when no per-call/global/env override is
-    active and auto would pick Pallas, operands smaller than this run the
-    oracle instead (kernel launch overhead dominates tiny stacks).
     """
 
-    __slots__ = ("name", "_jnp", "_pallas", "auto_jnp_below")
+    __slots__ = ("name", "_jnp", "_pallas")
 
-    def __init__(self, name: str, jnp_impl: Callable, pallas_impl: Callable,
-                 auto_jnp_below: int = 0):
+    def __init__(self, name: str, jnp_impl: Callable, pallas_impl: Callable):
         self.name = name
         self._jnp = jnp_impl
         self._pallas = pallas_impl
-        self.auto_jnp_below = auto_jnp_below
 
     def impl(self, backend: Optional[str] = None) -> Callable:
         b = _check_backend(backend) if backend else current_backend()
@@ -143,48 +131,36 @@ class Kernel:
             return lambda *a, **kw: self._pallas(*a, interpret=True, **kw)
         return lambda *a, **kw: self._pallas(*a, interpret=False, **kw)
 
-    def resolve_backend(self, *args, backend: Optional[str] = None) -> str:
-        """The backend this call would dispatch to (trace-time decision).
-
-        Explicit choices (per-call, global, env var) pass through
-        untouched; only the pure-auto path applies the size fallback,
-        reading the first operand's static element count.
-        """
+    def resolve_backend(self, backend: Optional[str] = None) -> str:
+        """The backend this call would dispatch to (trace-time decision),
+        most specific choice first: per-call, global, env var, auto."""
         if backend:
             return self._tally(_check_backend(backend), "call")
         if _GLOBAL_BACKEND:
             return self._tally(_GLOBAL_BACKEND, "global")
         if os.environ.get("REPRO_KERNEL_BACKEND"):
             return self._tally(default_backend(), "env")
-        b = default_backend()
-        if b == "pallas" and self.auto_jnp_below and args:
-            size = getattr(args[0], "size", None)
-            if size is not None and size < self.auto_jnp_below:
-                return self._tally("jnp", "auto_jnp_below")
-        return self._tally(b, "auto")
+        return self._tally(default_backend(), "auto")
 
     def _tally(self, backend: str, reason: str) -> str:
         _DISPATCH_COUNTS[(self.name, backend, reason)] += 1
         return backend
 
     def __call__(self, *args, backend: Optional[str] = None, **kwargs):
-        return self.impl(self.resolve_backend(*args, backend=backend)
-                         )(*args, **kwargs)
+        return self.impl(self.resolve_backend(backend))(*args, **kwargs)
 
     def __repr__(self) -> str:
         return f"Kernel({self.name!r})"
 
 
 def register_kernel(name: str, *, jnp_impl: Callable, pallas_impl: Callable,
-                    auto_jnp_below: int = 0, **meta) -> Kernel:
+                    **meta) -> Kernel:
     """Create a :class:`Kernel` and file it under the ``kernel`` registry
-    namespace (metadata, including ``auto_jnp_below``, lands in
-    ``REGISTRY.meta("kernel", name)``)."""
+    namespace (metadata lands in ``REGISTRY.meta("kernel", name)``)."""
     from repro.core.registry import REGISTRY
-    k = Kernel(name, jnp_impl, pallas_impl, auto_jnp_below=auto_jnp_below)
+    k = Kernel(name, jnp_impl, pallas_impl)
     _KERNELS[name] = k
-    REGISTRY.register("kernel", name, auto_jnp_below=auto_jnp_below,
-                      **meta)(lambda _k=k: _k)
+    REGISTRY.register("kernel", name, **meta)(lambda _k=k: _k)
     return k
 
 
@@ -197,12 +173,3 @@ def get_kernel(name: str) -> Kernel:
         return resolve("kernel", name)
     return _KERNELS[name]
 
-
-def tpu_compiler_params(**kwargs):
-    """Version-compat shim: jax renamed ``pltpu.TPUCompilerParams`` to
-    ``pltpu.CompilerParams`` (and back again across releases); pick
-    whichever this jax provides."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dispatch import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -108,7 +106,7 @@ def flash_attention_pallas(q, k, v, n_q_heads: int, window=None,
             pltpu.VMEM((bq, hp), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qp, kp, vp)
     return out[:, :Sq, :hd]

@@ -10,16 +10,10 @@ from repro.kernels.gossip_reduce import ref
 from repro.kernels.gossip_reduce.gossip_reduce import (
     gossip_reduce_pallas, neighbor_reduce_pallas)
 
-# auto-mode size cutoffs (first-operand elements): BENCH_kernels.json has
-# the kernel path *losing* to the oracle at (K=8, P=4, D=512) — msgs 4096
-# elements for gossip_reduce, recv 16384 for neighbor_reduce — and winning
-# from the next ladder point up; below the cutoff auto dispatches jnp.
 gossip_reduce = register_kernel(
     "gossip_reduce", jnp_impl=ref.gossip_reduce,
-    pallas_impl=gossip_reduce_pallas, modes=ref.MODES,
-    auto_jnp_below=8192)
+    pallas_impl=gossip_reduce_pallas, modes=ref.MODES)
 
 neighbor_reduce = register_kernel(
     "neighbor_reduce", jnp_impl=ref.neighbor_reduce,
-    pallas_impl=neighbor_reduce_pallas, modes=ref.MODES,
-    auto_jnp_below=32768)
+    pallas_impl=neighbor_reduce_pallas, modes=ref.MODES)

@@ -3,8 +3,5 @@ from repro.kernels.dispatch import register_kernel
 from repro.kernels.krum_score import ref
 from repro.kernels.krum_score.krum_score import krum_scores_pallas
 
-# launch-overhead cutoff: under ~2k stack elements the oracle wins
-# (BENCH_kernels.json smallest point); auto dispatches jnp below it
 krum_scores = register_kernel(
-    "krum_score", jnp_impl=ref.krum_scores, pallas_impl=krum_scores_pallas,
-    auto_jnp_below=2048)
+    "krum_score", jnp_impl=ref.krum_scores, pallas_impl=krum_scores_pallas)

@@ -8,9 +8,6 @@ from repro.kernels.dispatch import register_kernel
 from repro.kernels.pairwise_dist import ref
 from repro.kernels.pairwise_dist.pairwise_dist import pairwise_sq_dists_pallas
 
-# below ~2k stack elements the pallas_call launch overhead exceeds the
-# whole dense oracle (BENCH_kernels.json smallest-point margins); auto
-# falls back to jnp under the cutoff
 pairwise_sq_dists = register_kernel(
     "pairwise_dist", jnp_impl=ref.pairwise_sq_dists,
-    pallas_impl=pairwise_sq_dists_pallas, auto_jnp_below=2048)
+    pallas_impl=pairwise_sq_dists_pallas)

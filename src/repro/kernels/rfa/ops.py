@@ -3,7 +3,4 @@ from repro.kernels.dispatch import register_kernel
 from repro.kernels.rfa import ref
 from repro.kernels.rfa.rfa import rfa_pallas
 
-# launch-overhead cutoff: under ~2k stack elements the oracle wins
-# (BENCH_kernels.json smallest point); auto dispatches jnp below it
-rfa = register_kernel("rfa", jnp_impl=ref.rfa, pallas_impl=rfa_pallas,
-                      auto_jnp_below=2048)
+rfa = register_kernel("rfa", jnp_impl=ref.rfa, pallas_impl=rfa_pallas)

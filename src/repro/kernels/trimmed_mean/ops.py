@@ -8,8 +8,6 @@ from repro.kernels.dispatch import register_kernel
 from repro.kernels.trimmed_mean import ref
 from repro.kernels.trimmed_mean.trimmed_mean import trimmed_mean_pallas
 
-# launch-overhead cutoff: under ~2k stack elements the oracle wins
-# (BENCH_kernels.json smallest point); auto dispatches jnp below it
 trimmed_mean = register_kernel(
     "trimmed_mean", jnp_impl=ref.trimmed_mean,
-    pallas_impl=trimmed_mean_pallas, auto_jnp_below=2048)
+    pallas_impl=trimmed_mean_pallas)

@@ -17,13 +17,35 @@ Two modes share the `repro.serving` engine:
 d_model=64, n_heads=2)" --checkpoint results/policy.npz
 """
 import argparse
+import functools
 
 import jax
+import jax.numpy as jnp
 
 from repro import obs
 from repro.configs.base import get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import init_params
 from repro.serving import DecodeEngine, PolicyServer, make_traffic, serve
+
+
+def build_engine(cfg, seed: int, *, slots: int, max_new: int,
+                 max_prompt: int) -> DecodeEngine:
+    """Seeded random weights for ``cfg`` behind a :class:`DecodeEngine`.
+
+    Weights and KV cache are bf16, the dtype the published checkpoints
+    ship in. An f32 ``qwen2.5-3b`` (11.5 GiB) leaves too little of a 16 GiB
+    TPU v5e for the bf16 weight copies XLA hoists out of the layer scan
+    at the default matmul precision, which rounds f32 operands to bf16
+    anyway.
+    """
+    key_init, _ = jax.random.split(jax.random.PRNGKey(seed))
+    # jitted, so a published-width init holds only its outputs on the
+    # device (eagerly, each layer stack would briefly exist twice)
+    params = jax.jit(functools.partial(init_params, cfg,
+                                       dtype=jnp.bfloat16))(key_init)
+    return DecodeEngine(cfg, params, slots=slots, max_new=max_new,
+                        max_prompt=max_prompt, dtype=jnp.bfloat16)
 
 
 def main() -> None:
@@ -45,6 +67,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--offline", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.policy is not None:
         kw = {"checkpoint": args.checkpoint} if args.checkpoint else \
@@ -59,11 +82,9 @@ def main() -> None:
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
-    key_init, _ = jax.random.split(jax.random.PRNGKey(args.seed))
-    params = init_params(cfg, key_init)
-    engine = DecodeEngine(cfg, params, slots=args.slots, max_new=args.gen,
-                          max_prompt=args.prompt_len)
-    server = PolicyServer(engine)
+    server = PolicyServer(build_engine(cfg, args.seed, slots=args.slots,
+                                       max_new=args.gen,
+                                       max_prompt=args.prompt_len))
     traffic = make_traffic(
         args.requests, seed=args.seed, rate_rps=args.rate,
         max_new=args.gen, vocab=cfg.vocab_size,

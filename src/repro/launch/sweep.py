@@ -30,6 +30,7 @@ import contextlib
 import os
 
 from repro import obs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sweep import SweepRunner
 
 
@@ -112,6 +113,7 @@ def main() -> None:
         from repro.distributed.sharding import init_distributed
         init_distributed(args.coordinator, args.processes,
                          args.process_id)
+    enable_compile_cache()
 
     if args.resume is not None:
         runner = SweepRunner.resume(args.resume, mode=args.mode)

@@ -41,6 +41,7 @@ from repro.data.pipeline import DataConfig, TokenPipeline
 from repro.distributed.fed_trainer import (FedConfig, common_sample_coin,
                                            fed_train_step, fed_train_window,
                                            init_fed_state)
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _stack_batches(batches: list) -> dict:
@@ -80,6 +81,7 @@ def main() -> None:
                     help="host span tracing -> Chrome-trace trace.json "
                          "(implies telemetry; default DIR: telemetry/)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     out_dir = args.telemetry_out or ("telemetry" if args.profile else None)
     telemetry_on = out_dir is not None

@@ -1,7 +1,7 @@
 """Per-run manifest (DESIGN.md §8): the static facts a telemetry stream
 needs to be interpretable after the fact — versions, devices, mesh,
-compile counts, and the kernel-dispatch counters (which make a silent
-``auto_jnp_below`` fallback visible instead of only a 2x bench miss).
+compile counts, and the kernel-dispatch counters (which backend each
+traced kernel call baked in).
 """
 from __future__ import annotations
 

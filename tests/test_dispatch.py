@@ -131,41 +131,46 @@ def test_global_backend_reroutes_aggregator():
     np.testing.assert_allclose(rfa_j, rfa_p, atol=1e-4)
 
 
-def test_auto_size_threshold_falls_back_to_jnp(monkeypatch):
-    """Auto mode on TPU dispatches tiny stacks to the oracle: below a
-    kernel's ``auto_jnp_below`` first-operand element count the Pallas
-    launch overhead dominates, so auto picks jnp; at/above the cutoff it
-    stays on pallas. Every explicit choice bypasses the fallback."""
+def _auto_backends(kernel_name, shapes):
+    """Backends auto mode bakes into a trace of ``kernel_name`` at each
+    (K, d) stack shape — traced abstractly, so no kernel runs."""
+    k = dispatch.get_kernel(kernel_name)
+    picked = []
+    for K, d in shapes:
+        dispatch.reset_dispatch_counts()
+        jax.eval_shape(lambda x: k(x, 1), jax.ShapeDtypeStruct((K, d),
+                                                              jnp.float32))
+        (name, backend, reason), = dispatch.dispatch_counts()
+        assert (name, reason) == (kernel_name, "auto")
+        picked.append(backend)
+    return picked
+
+
+# from a 4-element stack to a transformer-sized one
+_SIZES = ((2, 2), (13, 386), (8, 512), (64, 4096), (13, 1 << 20))
+
+
+def test_auto_picks_pallas_at_every_size_on_tpu(monkeypatch):
+    """On TPU auto dispatches the compiled kernel whatever the operand
+    size: no size cutoff sends a small stack to the oracle. Explicit
+    choices still win over auto."""
     monkeypatch.delenv("REPRO_KERNEL_BACKEND", raising=False)
     monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
-    k = dispatch.get_kernel("gossip_reduce")
-    assert k.auto_jnp_below == 8192
-    small = jnp.ones((8, 512))            # 4096 < 8192
-    big = jnp.ones((8, 2048))             # 16384 >= 8192
-    assert k.resolve_backend(small) == "jnp"
-    assert k.resolve_backend(big) == "pallas"
-    # per-call override wins over the size fallback
-    assert k.resolve_backend(small, backend="pallas") == "pallas"
-    # global override wins
+    assert _auto_backends("trimmed_mean", _SIZES) == ["pallas"] * len(_SIZES)
+    k = dispatch.get_kernel("trimmed_mean")
+    assert k.resolve_backend("jnp") == "jnp"
     with dispatch.use_backend("pallas-interpret"):
-        assert k.resolve_backend(small) == "pallas-interpret"
-    # env var wins
-    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "pallas")
-    assert k.resolve_backend(small) == "pallas"
-    # cutoff is per-kernel metadata, visible through the registry
-    assert REGISTRY.meta("kernel", "gossip_reduce")["auto_jnp_below"] == 8192
-    assert REGISTRY.meta("kernel", "neighbor_reduce")["auto_jnp_below"] \
-        == 32768
+        assert k.resolve_backend() == "pallas-interpret"
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "jnp")
+    assert k.resolve_backend() == "jnp"
+    assert "auto_jnp_below" not in REGISTRY.meta("kernel", "gossip_reduce")
 
 
-def test_auto_threshold_inert_off_tpu(monkeypatch):
-    """Off-TPU auto already resolves to jnp; the size fallback never
-    flips anything."""
+def test_auto_picks_jnp_off_tpu(monkeypatch):
+    """Off TPU auto dispatches the jnp oracle at every size."""
     monkeypatch.delenv("REPRO_KERNEL_BACKEND", raising=False)
     monkeypatch.setattr(dispatch, "on_tpu", lambda: False)
-    k = dispatch.get_kernel("gossip_reduce")
-    assert k.resolve_backend(jnp.ones((8, 512))) == "jnp"
-    assert k.resolve_backend(jnp.ones((64, 4096))) == "jnp"
+    assert _auto_backends("trimmed_mean", _SIZES) == ["jnp"] * len(_SIZES)
 
 
 def test_block_d_stripped_for_jnp_oracle():

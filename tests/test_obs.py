@@ -262,7 +262,7 @@ class TestDispatchCounters:
         assert sum(counts.values()) == 1
         ((name, backend, reason),) = counts
         assert name == "krum_score" and backend in dispatch.BACKENDS
-        assert reason in ("auto", "auto_jnp_below")
+        assert reason == "auto"
         k(x, 2, backend="jnp")
         assert dispatch.dispatch_counts()[
             ("krum_score", "jnp", "call")] == 1

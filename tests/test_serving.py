@@ -168,6 +168,27 @@ def test_token_budgets_respected(policy, params, env):
         assert len(r.tokens) == budgets[r.uid]
 
 
+def test_lm_serving_builds_bf16_engine():
+    """The LM serving launcher keeps weights and KV cache in bf16, the
+    published checkpoints' dtype, and answers every request within its
+    budget with them."""
+    import jax.numpy as jnp
+    from repro.configs.base import get_config, reduced
+    from repro.launch.serve import build_engine
+    cfg = reduced(get_config("qwen2.5-3b"))
+    eng = build_engine(cfg, 0, slots=2, max_new=4, max_prompt=4)
+    assert {x.dtype for x in jax.tree.leaves(eng.params)} == \
+        {jnp.dtype(jnp.bfloat16)}
+    assert {x.dtype for x in jax.tree.leaves(eng.init_state().cache)
+            if jnp.issubdtype(x.dtype, jnp.floating)} == \
+        {jnp.dtype(jnp.bfloat16)}
+    traffic = make_traffic(4, seed=3, max_new=4, vocab=cfg.vocab_size,
+                           prompt_lens=(1, 4))
+    report = PolicyServer(eng, warmup=False).run_offline(traffic)
+    assert {r.uid: len(r.tokens) for r in report.results} == \
+        {r.uid: r.max_new for r in traffic}
+
+
 def test_no_recompile_per_request(policy, params, env):
     """After warmup, an entire request stream (mixed budgets, mixed
     arrival patterns, slot churn) triggers zero XLA compiles."""

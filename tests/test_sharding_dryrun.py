@@ -155,6 +155,73 @@ print("LANE_PAD_OK")
     assert "LANE_PAD_OK" in _run_subprocess(code)
 
 
+def test_lane_mesh_rows_equal_one_device():
+    """Each two-row group (one lane × two seeds) on a forced 4-device
+    mesh pads to two rows per device, and every row is bit-identical to
+    the same grid run on one device: a device holding a single row would
+    run the
+    program with a unit batch axis, which XLA compiles to different
+    arithmetic."""
+    code = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, numpy as np
+from repro.core.engine import ScenarioGrid, run_grid
+from repro.distributed.sharding import lane_mesh, padded_rows, use_lane_mesh
+from repro.rl.envs import make_cartpole
+
+mesh = lane_mesh()
+assert padded_rows(mesh, 2) == 8              # two rows on every device
+assert padded_rows(mesh, 9) == 12
+assert padded_rows(None, 1) == 1
+
+env = make_cartpole(horizon=10)
+grid = ScenarioGrid(seeds=(0, 1), axes={"aggregator": ("krum", "rfa")})
+kw = dict(algo="decbyzpg", K=5, n_byz=1, attack="large_noise",
+          N=4, B=2, kappa=1, hidden=(4,))
+spread = run_grid(env, grid, 5, **kw)
+with use_lane_mesh(None):
+    single = run_grid(env, grid, 5, **kw)
+for scn in single:
+    for k in ("returns", "samples", "diameter", "theta"):
+        np.testing.assert_array_equal(spread[scn][k], single[scn][k])
+print("LANE_ROWS_OK")
+"""
+    assert "LANE_ROWS_OK" in _run_subprocess(code)
+
+
+def test_flat_fed_state_built_sharded():
+    """On a model mesh every (K, D) stack of the flat federated state,
+    the optimizer moments included, is built D-sharded and no whole copy
+    stays on one device; the values equal the dense build."""
+    code = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import gc
+import jax, numpy as np
+from jax.sharding import Mesh, PartitionSpec as P
+from repro.configs.base import get_config, reduced
+from repro.distributed.fed_trainer import FedConfig, init_flat_fed_state
+mesh = Mesh(np.asarray(jax.devices()), ("model",))
+cfg = reduced(get_config("xlstm-350m"))
+fed = FedConfig(aggregator="rfa", optimizer="adam")
+key = jax.random.PRNGKey(0)
+state, _ = init_flat_fed_state(cfg, fed, 2, key, mesh=mesh)
+dense, _ = init_flat_fed_state(cfg, fed, 2, key)
+stacks = [x for x in jax.tree.leaves(state) if x.ndim == 2]
+assert len(stacks) == 5, len(stacks)
+assert all(x.sharding.spec == P(None, "model") for x in stacks)
+gc.collect()
+whole = [a for a in jax.live_arrays() if a.ndim == 2
+         and len(a.sharding.device_set) == 1 and a.shape == stacks[0].shape]
+assert len(whole) == 5, len(whole)          # the dense build only
+for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(dense)):
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+print("FLAT_SHARDED_OK")
+"""
+    assert "FLAT_SHARDED_OK" in _run_subprocess(code)
+
+
 def test_dryrun_results_if_present():
     """When the production sweep has run, every recorded pair must have
     lowered+compiled OK."""

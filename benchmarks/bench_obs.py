@@ -1,7 +1,7 @@
 """Telemetry-overhead benchmark (DESIGN.md §8).
 
 Measures the fused DecByzPG loop warm us/iteration with telemetry **off**
-(the default — must be the exact seed program) and **on** (in-loop taps
+(the default — no callback in the program) and **on** (in-loop taps
 streaming to a JSONL sink), at the smoke and fig1 sweep points. Rows land
 in ``benchmarks/BENCH_obs.json``:
 
@@ -19,11 +19,13 @@ fallback in ``check_regress.py`` instead of a hard-coded schema branch.
 
 ``--smoke`` runs only the smoke point and doubles as the CI telemetry
 artifact run: the on-path JSONL stream is written to the untracked
-``benchmarks/TELEMETRY_smoke.jsonl`` and the host-span Chrome trace to
-``benchmarks/TRACE_smoke.json`` (both uploaded by CI, loadable in
-Perfetto / chrome://tracing).
+``benchmarks/TELEMETRY_smoke.jsonl`` and a ``jax.profiler`` trace of the
+on path (host spans and device ops on one clock) into the untracked
+``benchmarks/TRACE_smoke/`` (both uploaded by CI; the ``.xplane.pb``
+loads in TensorBoard / XProf).
 """
 import argparse
+import contextlib
 import dataclasses
 import json
 import os
@@ -57,7 +59,7 @@ def _warm_us_per_iter(run, env, cfg, T) -> float:
 
 
 def measure(env_spec: str, T: int, base: dict, jsonl_path: str,
-            trace_path=None) -> list:
+            trace_dir=None) -> list:
     from repro import obs
     from repro.core.decbyzpg import DecByzPGConfig, run_decbyzpg
     from repro.rl.envs import make_env
@@ -67,12 +69,11 @@ def measure(env_spec: str, T: int, base: dict, jsonl_path: str,
     off_us = _warm_us_per_iter(run_decbyzpg, env, cfg, T)
 
     cfg_on = dataclasses.replace(cfg, telemetry=True)
-    obs.get_tracer().clear()
-    with obs.telemetry(obs.JsonlSink(jsonl_path)):
+    profile = (jax.profiler.trace(trace_dir) if trace_dir is not None
+               else contextlib.nullcontext())
+    with obs.telemetry(obs.JsonlSink(jsonl_path)), profile:
         with obs.host_span("bench_obs.fused_on", env=env_spec, T=T):
             on_us = _warm_us_per_iter(run_decbyzpg, env, cfg_on, T)
-    if trace_path is not None:
-        obs.write_trace(trace_path)
 
     shared = {"env": env_spec, "K": base["K"], "T": T}
     obs.progress(f"bench_obs {env_spec} K={base['K']} T={T}: "
@@ -94,7 +95,7 @@ def run(smoke: bool = False) -> dict:
         for i, (env_spec, T, base) in enumerate(sizes):
             if smoke:
                 jsonl = os.path.join(HERE, "TELEMETRY_smoke.jsonl")
-                trace = os.path.join(HERE, "TRACE_smoke.json")
+                trace = os.path.join(HERE, "TRACE_smoke")
             else:
                 jsonl = os.path.join(tmp, f"metrics_{i}.jsonl")
                 trace = None
@@ -117,7 +118,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="seconds-scale CI run (smoke point only); also "
                          "writes the TELEMETRY_smoke.jsonl / "
-                         "TRACE_smoke.json CI artifacts")
+                         "TRACE_smoke/ CI artifacts")
     args = ap.parse_args()
     run(smoke=args.smoke)
 

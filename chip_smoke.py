@@ -94,28 +94,6 @@ def gossip_arm(cfg):
     return cfg
 
 
-class CompileCounter:
-    """XLA compiles and persistent-cache hits, from JAX's monitoring
-    events (a cache hit also reports a backend-compile duration)."""
-
-    def __init__(self):
-        import jax
-        self.requests = self.hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.requests += 1
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-
-    def snapshot(self):
-        return self.requests - self.hits, self.hits
-
-
 def check(ok, value):
     return {"ok": bool(ok), "value": value}
 
@@ -418,7 +396,7 @@ def phase_dsharded() -> tuple:
 # ---------------------------------------------------------------------------
 
 def run_phase(name, fn, counter) -> bool:
-    c0, h0 = counter.snapshot()
+    s0 = counter.snapshot()
     t0 = time.perf_counter()
     try:
         values, checks = fn()
@@ -426,11 +404,11 @@ def run_phase(name, fn, counter) -> bool:
     except Exception as e:          # report the phase, then go on
         traceback.print_exc()
         values, checks, error = {}, {}, f"{type(e).__name__}: {e}"
-    c1, h1 = counter.snapshot()
+    d = counter.snapshot() - s0
     ok = error is None and all(c["ok"] for c in checks.values())
     line = {"phase": name, "ok": ok,
             "seconds": time.perf_counter() - t0,
-            "compiles": c1 - c0, "cache_hits": h1 - h0, **values,
+            "compiles": d.compiles, "cache_hits": d.cache_hits, **values,
             "checks": checks}
     if error is not None:
         line["error"] = error
@@ -452,6 +430,7 @@ def main() -> int:
         return 2
     sys.path.insert(0, SRC)
     import jax
+    from repro import obs
     from repro.kernels import dispatch
     from repro.launch.compile_cache import enable_compile_cache
 
@@ -471,7 +450,7 @@ def main() -> int:
               f"{len(devices)} device(s)", file=sys.stderr)
         return 1
     os.makedirs(OUT_DIR, exist_ok=True)
-    counter = CompileCounter()
+    counter = obs.compile_counter()
     phases = ({"lanes": phase_lanes, "dsharded": phase_dsharded}
               if args.chips == 4 else
               {"fleet": phase_fleet, "serve": phase_serve})

@@ -140,8 +140,7 @@ def avg_agree(theta: jnp.ndarray, kappa: int, n_byz: int,
               alpha_bar: Optional[float] = None,
               topology=None,
               kernel_backend: Optional[str] = None,
-              sharded: Optional[bool] = None,
-              telemetry: bool = False) -> jnp.ndarray:
+              sharded: Optional[bool] = None) -> jnp.ndarray:
     """Simulate Avg-Agree_κ over K agents (paper Algorithm 3, generalized
     to gossip graphs).
 
@@ -169,8 +168,7 @@ def avg_agree(theta: jnp.ndarray, kappa: int, n_byz: int,
     gather, so low-degree agents see extra copies of their own value.
     Returns the (K, d) post-agreement parameters (Byzantine rows carry the
     value an honest agent in that slot would compute; callers mask them).
-    ``telemetry`` labels the gossip-round body with a ``jax.named_scope``
-    (profile-readable HLO metadata; off, the program text is untouched).
+    Each gossip round's body carries the ``agree.round`` phase name.
     """
     K, d = theta.shape
     if kernel_backend is None:
@@ -208,7 +206,7 @@ def avg_agree(theta: jnp.ndarray, kappa: int, n_byz: int,
     rows = jnp.arange(K)[:, None]
 
     def one_round(th, k):
-        with obs.named_phase("agree.round", telemetry):
+        with obs.named_phase("agree.round"):
             return _round_body(th, k)
 
     def _round_body(th, k):

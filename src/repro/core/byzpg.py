@@ -65,7 +65,7 @@ class ByzPGConfig:
     baseline: float = 0.0
     seed: int = 0
     telemetry: bool = False     # static (in static_key): in-loop obs taps
-    # + per-round rejected-agent masks; off = exact seed program
+    # + per-round rejected-agent masks; off = no callback in the program
 
     def __post_init__(self):
         normalize_spec_fields(self, _SPEC_FIELDS)
@@ -81,9 +81,10 @@ def _optimizer(cfg):
 
 def init_byzpg_carry(env, cfg: ByzPGConfig, k_init):
     """(θ (d,), θ_prev, v_prev, opt_state) — traceable for grid lanes."""
-    vec0 = ravel(resolve_policy(cfg, env).init(k_init))[0]
-    opt_state = _optimizer(cfg).init(vec0)
-    return vec0, jnp.array(vec0), jnp.zeros_like(vec0), opt_state
+    with obs.named_phase("byzpg.init"):
+        vec0 = ravel(resolve_policy(cfg, env).init(k_init))[0]
+        opt_state = _optimizer(cfg).init(vec0)
+        return vec0, jnp.array(vec0), jnp.zeros_like(vec0), opt_state
 
 
 def build_byzpg_step(env, cfg: ByzPGConfig, traced=None):
@@ -125,44 +126,50 @@ def build_byzpg_step(env, cfg: ByzPGConfig, traced=None):
         prev = unravel(prev_vec)
 
         def one(k, scale):
-            traj = sample_batch(env, params, k, M, logits_spec,
-                                logit_scale=scale)
-            g = ravel(grad_estimate(params, traj, gamma, baseline,
-                                    cfg.estimator, logits_spec,
-                                    sample_weights=w))[0]
-            g_old = ravel(weighted_grad_estimate(
-                prev, params, traj, gamma, baseline,
-                cfg.estimator, logits_spec,
-                sample_weights=w_small))[0]
-            return g, g_old, jnp.sum(w * batch_return(traj))
+            with obs.named_phase("byzpg.rollout"):
+                traj = sample_batch(env, params, k, M, logits_spec,
+                                    logit_scale=scale)
+                ret = jnp.sum(w * batch_return(traj))
+            with obs.named_phase("byzpg.gradient"):
+                g = ravel(grad_estimate(params, traj, gamma, baseline,
+                                        cfg.estimator, logits_spec,
+                                        sample_weights=w))[0]
+                g_old = ravel(weighted_grad_estimate(
+                    prev, params, traj, gamma, baseline,
+                    cfg.estimator, logits_spec,
+                    sample_weights=w_small))[0]
+            return g, g_old, ret
 
-        with obs.named_phase("byzpg.estimate", cfg.telemetry):
+        with obs.named_phase("byzpg.estimate"):
             g, g_old, rets = jax.vmap(one)(jax.random.split(k_traj, cfg.K),
                                            scales)
-        with obs.named_phase("byzpg.aggregate", cfg.telemetry):
+        with obs.named_phase("byzpg.aggregate"):
             msgs = attack(g, byz_mask, k_att)
             v_large = agg(msgs, k_agg)
-        # small step: w == w_small, so g[server] is exactly ĝ_B(θ_t) on the
-        # server's fresh batch and g_old[server] the IS estimate at θ_prev.
-        v_page = g[server] + v_prev - g_old[server]
-        v = jnp.where(coin, v_large, v_page)
-        new_vec, opt_state = opt.update(v, opt_state, vec)
-        honest_ret = jnp.sum(jnp.where(byz_mask, 0.0, rets)) \
-            / jnp.maximum(jnp.sum(~byz_mask), 1)
-        ret = jnp.where(coin, honest_ret, rets[server])
-        if not cfg.telemetry:
-            return (new_vec, vec, v, opt_state), (ret, coin)
-        # observers only (no extra PRNG consumption): the aggregation is
-        # live on large rounds; small rounds still score the attacked
-        # worker messages the server would have received
-        norms = jnp.linalg.norm(g, axis=1)
-        grad_norm = jnp.sum(jnp.where(byz_mask, 0.0, norms)) \
-            / jnp.maximum(jnp.sum(~byz_mask), 1)
-        rejected = rejection_mask(cfg.aggregator, msgs, cfg.n_byz)
-        obs.tap("byzpg", t=t, coin=coin, honest_return=ret,
-                grad_norm=grad_norm, rejected=rejected)
-        return (new_vec, vec, v, opt_state), (ret, coin, grad_norm,
-                                              rejected)
+        with obs.named_phase("byzpg.update"):
+            # small step: w == w_small, so g[server] is exactly ĝ_B(θ_t)
+            # on the server's fresh batch and g_old[server] the IS
+            # estimate at θ_prev.
+            v_page = g[server] + v_prev - g_old[server]
+            v = jnp.where(coin, v_large, v_page)
+            new_vec, opt_state = opt.update(v, opt_state, vec)
+        with obs.named_phase("byzpg.diagnostics"):
+            honest_ret = jnp.sum(jnp.where(byz_mask, 0.0, rets)) \
+                / jnp.maximum(jnp.sum(~byz_mask), 1)
+            ret = jnp.where(coin, honest_ret, rets[server])
+            if not cfg.telemetry:
+                return (new_vec, vec, v, opt_state), (ret, coin)
+            # observers only (no extra PRNG consumption): the aggregation
+            # is live on large rounds; small rounds still score the
+            # attacked worker messages the server would have received
+            norms = jnp.linalg.norm(g, axis=1)
+            grad_norm = jnp.sum(jnp.where(byz_mask, 0.0, norms)) \
+                / jnp.maximum(jnp.sum(~byz_mask), 1)
+            rejected = rejection_mask(cfg.aggregator, msgs, cfg.n_byz)
+            obs.tap("byzpg", t=t, coin=coin, honest_return=ret,
+                    grad_norm=grad_norm, rejected=rejected)
+            return (new_vec, vec, v, opt_state), (ret, coin, grad_norm,
+                                                  rejected)
 
     return step
 

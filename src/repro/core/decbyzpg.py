@@ -76,7 +76,7 @@ class DecByzPGConfig:
     optimizer: object = "adam"  # paper App. D applies Adam to the PAGE
     seed: int = 0               # direction; "sgd" = Algorithm 2 line 8
     telemetry: bool = False     # static (in static_key): in-loop obs taps
-    # + per-round rejected-agent masks; off = exact seed program
+    # + per-round rejected-agent masks; off = no callback in the program
 
     def __post_init__(self):
         normalize_spec_fields(self, _SPEC_FIELDS)
@@ -93,10 +93,11 @@ def _optimizer(cfg: DecByzPGConfig):
 def init_decbyzpg_carry(env, cfg: DecByzPGConfig, k_init):
     """(θ_0 (K,d) common init, θ_prev, per-agent optimizer state) —
     traceable, so a grid lane can build its own carry under vmap."""
-    vec0 = ravel(resolve_policy(cfg, env).init(k_init))[0]
-    theta0 = jnp.tile(vec0, (cfg.K, 1))
-    opt0 = jax.vmap(_optimizer(cfg).init)(theta0)
-    return theta0, jnp.array(theta0), opt0
+    with obs.named_phase("decbyzpg.init"):
+        vec0 = ravel(resolve_policy(cfg, env).init(k_init))[0]
+        theta0 = jnp.tile(vec0, (cfg.K, 1))
+        opt0 = jax.vmap(_optimizer(cfg).init)(theta0)
+        return theta0, jnp.array(theta0), opt0
 
 
 def build_decbyzpg_step(env, cfg: DecByzPGConfig, traced=None):
@@ -143,17 +144,20 @@ def build_decbyzpg_step(env, cfg: DecByzPGConfig, traced=None):
     def agent_estimate(theta_vec, theta_prev_vec, key, w, scale):
         params = unravel(theta_vec)
         prev = unravel(theta_prev_vec)
-        traj = sample_batch(env, params, key, M, logits_spec,
-                            logit_scale=scale)
-        g = ravel(grad_estimate(params, traj, gamma, baseline,
-                                cfg.estimator, logits_spec,
-                                sample_weights=w))[0]
-        # IS-corrected estimate at θ_prev on the small-batch slice; masked
-        # out on large steps by the coin select below.
-        g_old = ravel(weighted_grad_estimate(
-            prev, params, traj, gamma, baseline,
-            cfg.estimator, logits_spec, sample_weights=w_small))[0]
-        return g, g_old, jnp.sum(w * batch_return(traj))
+        with obs.named_phase("decbyzpg.rollout"):
+            traj = sample_batch(env, params, key, M, logits_spec,
+                                logit_scale=scale)
+            ret = jnp.sum(w * batch_return(traj))
+        with obs.named_phase("decbyzpg.gradient"):
+            g = ravel(grad_estimate(params, traj, gamma, baseline,
+                                    cfg.estimator, logits_spec,
+                                    sample_weights=w))[0]
+            # IS-corrected estimate at θ_prev on the small-batch slice;
+            # masked out on large steps by the coin select below.
+            g_old = ravel(weighted_grad_estimate(
+                prev, params, traj, gamma, baseline,
+                cfg.estimator, logits_spec, sample_weights=w_small))[0]
+        return g, g_old, ret
 
     def step(carry, xs, coin_key):
         theta, theta_prev, opt_state = carry  # theta: (K, d)
@@ -161,42 +165,48 @@ def build_decbyzpg_step(env, cfg: DecByzPGConfig, traced=None):
         coin = engine.page_coin(coin_key, t, switch_p)
         w = jnp.where(coin, w_large, w_small)
         k_traj, k_att, k_agg, k_agr = jax.random.split(key, 4)
-        with obs.named_phase("decbyzpg.estimate", cfg.telemetry):
+        # every region is a named phase (DESIGN.md §8); the estimate's
+        # own ops are the PAGE correction
+        with obs.named_phase("decbyzpg.estimate"):
             g, g_old, rets = jax.vmap(
                 lambda tv, tp, k, s: agent_estimate(tv, tp, k, w, s)
             )(theta, theta_prev, jax.random.split(k_traj, cfg.K), scales)
             page = (theta - theta_prev) / eta - g_old
             tilde_v = jnp.where(coin, g, g + page)
-        with obs.named_phase("decbyzpg.aggregate", cfg.telemetry):
+        with obs.named_phase("decbyzpg.aggregate"):
             msgs = attack(tilde_v, byz_mask, k_att)
             # every agent aggregates the same broadcast set (v^(k));
             # per-receiver inconsistency is exercised inside Avg-Agree.
             v = jax.vmap(lambda k: agg(msgs, k))(
                 jax.random.split(k_agg, cfg.K))
-        theta_tilde, opt_state = jax.vmap(opt.update)(v, opt_state, theta)
-        with obs.named_phase("decbyzpg.agree", cfg.telemetry):
+        with obs.named_phase("decbyzpg.update"):
+            theta_tilde, opt_state = jax.vmap(opt.update)(v, opt_state,
+                                                          theta)
+        with obs.named_phase("decbyzpg.agree"):
             if cfg.kappa > 0:
                 theta_new = avg_agree(theta_tilde, cfg.kappa, cfg.n_byz,
                                       byz_mask, cfg.agreement, agr_attack,
-                                      k_agr, topology=topo,
-                                      telemetry=cfg.telemetry)
+                                      k_agr, topology=topo)
             else:
                 theta_new = theta_tilde
-        honest_ret = jnp.sum(jnp.where(byz_mask, 0.0, rets)) \
-            / jnp.maximum(jnp.sum(~byz_mask), 1)
-        diam = honest_diameter(theta_new, ~byz_mask)
-        if not cfg.telemetry:
-            return (theta_new, theta, opt_state), (honest_ret, coin, diam)
-        # telemetry plane: observers only — no extra PRNG consumption, so
-        # the returns/diameter histories are identical to the off path
-        norms = jnp.linalg.norm(tilde_v, axis=1)
-        grad_norm = jnp.sum(jnp.where(byz_mask, 0.0, norms)) \
-            / jnp.maximum(jnp.sum(~byz_mask), 1)
-        rejected = rejection_mask(cfg.aggregator, msgs, cfg.n_byz)
-        obs.tap("decbyzpg", t=t, coin=coin, honest_return=honest_ret,
-                diameter=diam, grad_norm=grad_norm, rejected=rejected)
-        return (theta_new, theta, opt_state), \
-            (honest_ret, coin, diam, grad_norm, rejected)
+        with obs.named_phase("decbyzpg.diagnostics"):
+            honest_ret = jnp.sum(jnp.where(byz_mask, 0.0, rets)) \
+                / jnp.maximum(jnp.sum(~byz_mask), 1)
+            diam = honest_diameter(theta_new, ~byz_mask)
+            if not cfg.telemetry:
+                return (theta_new, theta, opt_state), \
+                    (honest_ret, coin, diam)
+            # telemetry plane: observers only — no extra PRNG consumption,
+            # so the returns/diameter histories are identical to the off
+            # path
+            norms = jnp.linalg.norm(tilde_v, axis=1)
+            grad_norm = jnp.sum(jnp.where(byz_mask, 0.0, norms)) \
+                / jnp.maximum(jnp.sum(~byz_mask), 1)
+            rejected = rejection_mask(cfg.aggregator, msgs, cfg.n_byz)
+            obs.tap("decbyzpg", t=t, coin=coin, honest_return=honest_ret,
+                    diameter=diam, grad_norm=grad_norm, rejected=rejected)
+            return (theta_new, theta, opt_state), \
+                (honest_ret, coin, diam, grad_norm, rejected)
 
     return step
 

@@ -44,8 +44,8 @@ class FedConfig:
     mix_dtype: Optional[str] = None  # None | "bfloat16" (§Perf opt)
     mix_block: int = 0               # stream agreement in K-blocks (§Perf)
     seed: int = 0
-    telemetry: bool = False          # static: in-step obs taps + phases;
-    # off = the exact pre-telemetry program (no debug_callback in jaxpr)
+    telemetry: bool = False          # static: in-step obs taps;
+    # off = no debug_callback in the jaxpr
 
     def __post_init__(self):
         normalize_spec_fields(self, ("aggregator", "attack", "optimizer"))
@@ -92,7 +92,7 @@ def fed_train_step(cfg: ModelConfig, fed: FedConfig, state: FedState,
     grad_fn = jax.grad(lambda p, b: _loss(cfg, p, b))
     loss_fn = jax.value_and_grad(lambda p, b: _loss(cfg, p, b))
 
-    with obs.named_phase("fed.estimate", fed.telemetry):
+    with obs.named_phase("fed.estimate"):
         losses, g_new = jax.vmap(loss_fn)(state.params, batch)
 
         def _page(_):
@@ -107,7 +107,7 @@ def fed_train_step(cfg: ModelConfig, fed: FedConfig, state: FedState,
 
     K = byz_mask.shape[0]
     k_att, k_agg = jax.random.split(key)
-    with obs.named_phase("fed.aggregate", fed.telemetry):
+    with obs.named_phase("fed.aggregate"):
         if K == 1:
             v = tilde_v    # single-agent federation: aggregation is identity
         else:
@@ -116,33 +116,35 @@ def fed_train_step(cfg: ModelConfig, fed: FedConfig, state: FedState,
             v = agg_lib.aggregate(fed.aggregator, tilde_v, fed.n_byz, k_agg)
 
     opt = get_optimizer(fed.optimizer, fed.lr, maximize=False)
-    new_params, new_opt = jax.vmap(opt.update)(v, state.opt_state,
-                                               state.params)
+    with obs.named_phase("fed.update"):
+        new_params, new_opt = jax.vmap(opt.update)(v, state.opt_state,
+                                                   state.params)
     mix_dtype = jnp.bfloat16 if fed.mix_dtype == "bfloat16" else None
-    with obs.named_phase("fed.agree", fed.telemetry):
+    with obs.named_phase("fed.agree"):
         new_params = agg_lib.gda_agree(new_params, fed.kappa, fed.alpha_bar,
                                        mix_dtype=mix_dtype,
                                        block=fed.mix_block)
 
-    metrics = {
-        "loss": jnp.mean(jnp.where(byz_mask, 0.0, losses))
-        * byz_mask.shape[0] / jnp.maximum(jnp.sum(~byz_mask), 1),
-        # K=1: diameter is identically 0 (and the pairwise tensordot would
-        # force an all-gather of the full parameter stack)
-        "diameter": (jnp.zeros(()) if K == 1 else jnp.sqrt(jnp.max(
-            agg_lib.stacked_sq_dists(new_params)))),
-    }
-    if fed.telemetry:
-        # observers only: per-agent honest gradient norms, computed
-        # leaf-wise so the model-sharded stacks are never gathered
-        sq = sum(jnp.sum(jnp.reshape(l, (K, -1)) ** 2, axis=1)
-                 for l in jax.tree.leaves(tilde_v))
-        metrics["grad_norm"] = jnp.sum(
-            jnp.where(byz_mask, 0.0, jnp.sqrt(sq))) \
-            / jnp.maximum(jnp.sum(~byz_mask), 1)
-        obs.tap("fed", step=state.step, loss=metrics["loss"],
-                diameter=metrics["diameter"],
-                grad_norm=metrics["grad_norm"])
+    with obs.named_phase("fed.diagnostics"):
+        metrics = {
+            "loss": jnp.mean(jnp.where(byz_mask, 0.0, losses))
+            * byz_mask.shape[0] / jnp.maximum(jnp.sum(~byz_mask), 1),
+            # K=1: diameter is identically 0 (and the pairwise tensordot would
+            # force an all-gather of the full parameter stack)
+            "diameter": (jnp.zeros(()) if K == 1 else jnp.sqrt(jnp.max(
+                agg_lib.stacked_sq_dists(new_params)))),
+        }
+        if fed.telemetry:
+            # observers only: per-agent honest gradient norms, computed
+            # leaf-wise so the model-sharded stacks are never gathered
+            sq = sum(jnp.sum(jnp.reshape(l, (K, -1)) ** 2, axis=1)
+                     for l in jax.tree.leaves(tilde_v))
+            metrics["grad_norm"] = jnp.sum(
+                jnp.where(byz_mask, 0.0, jnp.sqrt(sq))) \
+                / jnp.maximum(jnp.sum(~byz_mask), 1)
+            obs.tap("fed", step=state.step, loss=metrics["loss"],
+                    diameter=metrics["diameter"],
+                    grad_norm=metrics["grad_norm"])
     new_state = FedState(new_params, state.params, v, new_opt,
                          state.step + 1)
     return new_state, metrics
@@ -296,7 +298,7 @@ def fed_train_step_flat(cfg: ModelConfig, fed: FedConfig,
     def loss_vec(vec, b):
         return _loss(cfg, unravel(vec), b)
 
-    with obs.named_phase("fed.estimate", fed.telemetry):
+    with obs.named_phase("fed.estimate"):
         losses, g_new = jax.vmap(jax.value_and_grad(loss_vec))(state.theta,
                                                                batch)
 
@@ -311,7 +313,7 @@ def fed_train_step_flat(cfg: ModelConfig, fed: FedConfig,
 
     K = byz_mask.shape[0]
     k_att, k_agg = jax.random.split(key)
-    with obs.named_phase("fed.aggregate", fed.telemetry):
+    with obs.named_phase("fed.aggregate"):
         if K == 1:
             v = tilde_v
         else:
@@ -323,32 +325,34 @@ def fed_train_step_flat(cfg: ModelConfig, fed: FedConfig,
                                  state.theta.shape)
 
     opt = get_optimizer(fed.optimizer, fed.lr, maximize=False)
-    new_theta, new_opt = jax.vmap(opt.update)(v, state.opt_state,
-                                              state.theta)
+    with obs.named_phase("fed.update"):
+        new_theta, new_opt = jax.vmap(opt.update)(v, state.opt_state,
+                                                  state.theta)
     mix_dtype = jnp.bfloat16 if fed.mix_dtype == "bfloat16" else None
-    with obs.named_phase("fed.agree", fed.telemetry):
+    with obs.named_phase("fed.agree"):
         new_theta = agg_lib.gda_agree(new_theta, fed.kappa, fed.alpha_bar,
                                       mix_dtype=mix_dtype,
                                       block=fed.mix_block)
-    metrics = {
-        "loss": jnp.mean(jnp.where(byz_mask, 0.0, losses))
-        * K / jnp.maximum(jnp.sum(~byz_mask), 1),
-        "diameter": (jnp.zeros(()) if K == 1 else jnp.sqrt(jnp.max(
-            agg_lib.stacked_sq_dists(new_theta)))),
-    }
-    if fed.telemetry:
-        norms = jnp.linalg.norm(tilde_v, axis=1)
-        metrics["grad_norm"] = jnp.sum(jnp.where(byz_mask, 0.0, norms)) \
-            / jnp.maximum(jnp.sum(~byz_mask), 1)
-        # the flat (K, D) stack is what the suspicion scores operate on —
-        # the tree-shaped trainer has no rejected-mask plane
-        metrics["rejected"] = (jnp.zeros((K,), bool) if K == 1 else
-                               rejection_mask(fed.aggregator, tilde_v,
-                                              fed.n_byz))
-        obs.tap("fed", step=state.step, loss=metrics["loss"],
-                diameter=metrics["diameter"],
-                grad_norm=metrics["grad_norm"],
-                rejected=metrics["rejected"])
+    with obs.named_phase("fed.diagnostics"):
+        metrics = {
+            "loss": jnp.mean(jnp.where(byz_mask, 0.0, losses))
+            * K / jnp.maximum(jnp.sum(~byz_mask), 1),
+            "diameter": (jnp.zeros(()) if K == 1 else jnp.sqrt(jnp.max(
+                agg_lib.stacked_sq_dists(new_theta)))),
+        }
+        if fed.telemetry:
+            norms = jnp.linalg.norm(tilde_v, axis=1)
+            metrics["grad_norm"] = jnp.sum(jnp.where(byz_mask, 0.0, norms)) \
+                / jnp.maximum(jnp.sum(~byz_mask), 1)
+            # the flat (K, D) stack is what the suspicion scores operate on —
+            # the tree-shaped trainer has no rejected-mask plane
+            metrics["rejected"] = (jnp.zeros((K,), bool) if K == 1 else
+                                   rejection_mask(fed.aggregator, tilde_v,
+                                                  fed.n_byz))
+            obs.tap("fed", step=state.step, loss=metrics["loss"],
+                    diameter=metrics["diameter"],
+                    grad_norm=metrics["grad_norm"],
+                    rejected=metrics["rejected"])
     return FlatFedState(new_theta, state.theta, v, new_opt,
                         state.step + 1), metrics
 

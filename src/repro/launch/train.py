@@ -16,9 +16,11 @@ production mesh.
 
 Telemetry (DESIGN.md §8): ``--telemetry-out DIR`` turns on the obs layer —
 ``fed.telemetry=True`` in-step taps streamed to ``DIR/metrics.jsonl`` plus
-a ``DIR/manifest.json`` run manifest; ``--profile`` additionally writes a
-Chrome-trace ``DIR/trace.json`` (Perfetto/chrome://tracing-loadable) of
-the host-side window spans and engine compiles.
+a ``DIR/manifest.json`` run manifest; ``--profile`` additionally records
+a ``jax.profiler`` trace into ``DIR`` (``DIR/plugins/profile/<run>/
+*.xplane.pb``; TensorBoard/XProf-loadable): the host-side window spans,
+engine builds and garbage collections on the same clock as the device
+operations, each named by its program phase.
 
   PYTHONPATH=src python -m repro.launch.train --arch llama3.2-1b --reduced \
       --agents 4 --steps 30 --byz 1 --attack large_noise
@@ -75,11 +77,12 @@ def main() -> None:
                     help="legacy per-step dispatch (two compiled programs)")
     ap.add_argument("--telemetry-out", default=None, metavar="DIR",
                     help="enable telemetry; write metrics.jsonl + "
-                         "manifest.json (and trace.json with --profile) "
-                         "under DIR")
+                         "manifest.json (and a profiler trace with "
+                         "--profile) under DIR")
     ap.add_argument("--profile", action="store_true",
-                    help="host span tracing -> Chrome-trace trace.json "
-                         "(implies telemetry; default DIR: telemetry/)")
+                    help="record a jax.profiler trace (host spans and "
+                         "device ops) into DIR (implies telemetry; "
+                         "default DIR: telemetry/)")
     args = ap.parse_args()
     enable_compile_cache()
 
@@ -109,11 +112,12 @@ def main() -> None:
 
     if telemetry_on:
         os.makedirs(out_dir, exist_ok=True)
-        obs.get_tracer().clear()
         tele = obs.telemetry(
             obs.JsonlSink(os.path.join(out_dir, "metrics.jsonl")))
     else:
         tele = contextlib.nullcontext()
+    profile = (jax.profiler.trace(out_dir) if args.profile
+               else contextlib.nullcontext())
 
     obs.progress(
         f"arch={cfg.name} K={K} byz={args.byz} attack={fed.attack} "
@@ -127,7 +131,7 @@ def main() -> None:
                      f"diam={float(metrics['diameter']):.3e} "
                      f"({time.time() - t0:.1f}s)", step=step_i)
 
-    with tele:
+    with tele, profile:
         if args.no_fused:
             steps = {True: jax.jit(lambda s, b, m, k: fed_train_step(
                          cfg, fed, s, b, m, k, large=True)),
@@ -174,8 +178,6 @@ def main() -> None:
                        "aggregator": str(fed.aggregator),
                        "steps": args.steps, "window": args.window,
                        "mode": "legacy" if args.no_fused else "fused"})
-            if args.profile:
-                obs.write_trace(os.path.join(out_dir, "trace.json"))
             obs.progress(f"telemetry written to {out_dir}/")
 
 

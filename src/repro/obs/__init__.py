@@ -1,17 +1,21 @@
 """repro.obs — zero-overhead-off telemetry (DESIGN.md §8).
 
-Three planes:
+Four planes:
 
 * **In-loop metric taps** (:mod:`repro.obs.metrics`): fused loops built
   with a static ``cfg.telemetry=True`` stream per-iteration scalars and
   small vectors (honest gradient norm, agreement diameter Δ₂, per-round
   rejected-agent masks) through ``jax.debug.callback`` into host ring
-  buffers and attached sinks. Off (the default) the compiled program is
-  the exact seed program.
+  buffers and attached sinks. Off (the default) the compiled program
+  holds no callback.
 * **Span tracing** (:mod:`repro.obs.trace`): ``jax.named_scope`` phase
-  names inside telemetry-enabled programs, plus a host Chrome-trace
-  tracer around engine compiles/dispatches (Perfetto-loadable;
-  ``--profile`` on ``repro.launch.train``).
+  names in every program (metadata only: free at run time and outside
+  the persistent-cache key), plus host spans around engine
+  compiles/dispatches and garbage collections as ``jax.profiler``
+  annotations, on the device operations' clock (``--profile`` on
+  ``repro.launch.train``).
+* **Compile counters** (:mod:`repro.obs.compiles`): traces, lowerings,
+  backend compiles and persistent-cache hits, with their seconds.
 * **Sinks + manifest** (:mod:`repro.obs.sinks` /
   :mod:`repro.obs.manifest`): JSONL / in-memory / stdout-progress sinks
   and a per-run manifest including the kernel backend-dispatch counters.
@@ -23,6 +27,7 @@ Typical use::
         out = run_decbyzpg(env, dataclasses.replace(cfg, telemetry=True), T)
     print(out["aggregator_confusion"]["recall"])
 """
+from repro.obs.compiles import CompileStats, compile_counter
 from repro.obs.manifest import build_manifest, write_manifest
 from repro.obs.metrics import (RingBuffer, Recorder, capture,
                                confusion_tally, disable, enable, enabled,
@@ -30,14 +35,12 @@ from repro.obs.metrics import (RingBuffer, Recorder, capture,
                                telemetry)
 from repro.obs.sinks import (JsonlSink, MemorySink, Sink,
                              StdoutProgressSink)
-from repro.obs.trace import (Tracer, get_tracer, host_instant, host_span,
-                             named_phase, write_trace)
+from repro.obs.trace import host_span, named_phase
 
 __all__ = [
     "RingBuffer", "Recorder", "Sink", "MemorySink", "JsonlSink",
-    "StdoutProgressSink", "Tracer",
+    "StdoutProgressSink", "CompileStats", "compile_counter",
     "enabled", "enable", "disable", "telemetry", "capture",
     "get_recorder", "record", "progress", "tap", "confusion_tally",
-    "named_phase", "host_span", "host_instant", "get_tracer",
-    "write_trace", "build_manifest", "write_manifest",
+    "named_phase", "host_span", "build_manifest", "write_manifest",
 ]

@@ -9,7 +9,7 @@ Two switches govern telemetry, by design:
   compile-cache entries.
 * :func:`enable` / :func:`disable` (process-global) gate the *host-side*
   instrumentation — engine spans, ``run_grid`` progress events, manifest
-  records — which must cost nothing when off.
+  records, the ``host.gc`` spans — which must cost nothing when off.
 
 A telemetry-enabled program emits per-iteration records through
 :func:`tap`, which lowers to one ``jax.debug.callback`` per scan step;
@@ -46,12 +46,18 @@ def enabled() -> bool:
     return _ENABLED[0]
 
 
+def _set_enabled(on: bool) -> None:
+    from repro.obs.trace import gc_spans   # trace imports this module
+    _ENABLED[0] = on
+    gc_spans(on)
+
+
 def enable() -> None:
-    _ENABLED[0] = True
+    _set_enabled(True)
 
 
 def disable() -> None:
-    _ENABLED[0] = False
+    _set_enabled(False)
 
 
 class RingBuffer:
@@ -145,13 +151,13 @@ def telemetry(*sinks: Sink, keep: bool = False):
     scope; yields the recorder. ``keep=True`` leaves attached sinks in
     place on exit (callers manage ``close``)."""
     prev = _ENABLED[0]
-    _ENABLED[0] = True
+    _set_enabled(True)
     for s in sinks:
         _RECORDER.add_sink(s)
     try:
         yield _RECORDER
     finally:
-        _ENABLED[0] = prev
+        _set_enabled(prev)
         if not keep:
             for s in sinks:
                 _RECORDER.remove_sink(s)

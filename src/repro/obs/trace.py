@@ -1,31 +1,33 @@
-"""Span tracing (DESIGN.md §8): device-program phase names + a host
-Chrome-trace tracer.
+"""Span tracing (DESIGN.md §8): device-program phase names and host
+spans, both on the profiler's clock.
 
-Two complementary layers:
+* :func:`named_phase` wraps a region of *traced* code in
+  ``jax.named_scope``. The name lands in the ``op_name`` metadata of
+  every instruction the region compiles to, so the compiled program's
+  text, and with it a ``jax.profiler`` capture, tells which phase
+  (rollout, gradient, aggregate, agree, ...) each device operation
+  belongs to. Names are metadata: the optimized program is the same
+  with or without them, so they cost nothing at run time, and JAX's
+  persistent-cache key strips them (unless
+  ``jax_compilation_cache_include_metadata_in_key`` is set). So they are
+  always on; ``cfg.telemetry`` gates only the in-loop taps.
+* :func:`host_span` enters a ``jax.profiler.TraceAnnotation`` while host
+  instrumentation is on (:func:`repro.obs.enable` /
+  :func:`repro.obs.telemetry`): engine builds, ``run_grid`` lane groups,
+  ``launch/train.py`` windows. A profiler trace then holds the host
+  spans and the device operations on one clock.
+* :func:`gc_spans` — while host instrumentation is on, every Python
+  garbage collection is wrapped in a ``host.gc`` annotation carrying its
+  generation, so a device idle gap can be put down to a collection, or
+  ruled out.
 
-* :func:`named_phase` — wraps a region of *traced* code in
-  ``jax.named_scope`` so the rollout/estimate/aggregate/agree phases are
-  identifiable in XLA dumps and ``jax.profiler`` captures. It is only
-  applied when the config's static ``telemetry`` flag is on, because name
-  metadata participates in program identity and the off path must compile
-  to the exact seed program.
-* :class:`Tracer` — a host-side wall-clock tracer emitting
-  Chrome-trace-event JSON (``{"traceEvents": [...]}``), loadable in
-  Perfetto / ``chrome://tracing``. ``engine.py`` wraps loop-cache builds
-  and per-lane-group dispatches in :func:`host_span`, so the trace shows
-  compile vs execute wall time per lane group and cache hits/misses.
-  Host spans additionally enter ``jax.profiler.TraceAnnotation`` so they
-  line up with device events when a profiler session is active.
-
-Host spans are no-ops (a shared ``nullcontext``) while telemetry is
-disabled — the hot loops must not pay for instrumentation that is off.
+Off, :func:`host_span` is a shared ``nullcontext`` and no GC callback is
+registered: the hot loops pay for nothing.
 """
 from __future__ import annotations
 
 import contextlib
-import json
-import time
-from typing import Optional
+import gc
 
 import jax
 
@@ -33,82 +35,45 @@ from repro.obs import metrics as _metrics
 
 _NULL = contextlib.nullcontext()
 
-
-def named_phase(name: str, enabled: bool = True):
-    """``jax.named_scope(name)`` when ``enabled`` (a static config flag),
-    else a no-op context — the off path's jaxpr keeps its historical
-    name stack."""
-    return jax.named_scope(name) if enabled else _NULL
+#: the host span around each garbage collection
+GC_SPAN = "host.gc"
 
 
-class Tracer:
-    """Accumulates Chrome trace events (host wall-clock, us since the
-    tracer's epoch)."""
-
-    def __init__(self):
-        self.events: list = []
-        self._t0 = time.perf_counter()
-
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
-
-    @contextlib.contextmanager
-    def span(self, name: str, **args):
-        """Complete ("X") event around the scope; ``args`` must be
-        JSON-serializable."""
-        t0 = self._now_us()
-        try:
-            ann = jax.profiler.TraceAnnotation(name)
-        except Exception:                      # profiler backend absent
-            ann = contextlib.nullcontext()
-        try:
-            with ann:
-                yield
-        finally:
-            self.events.append({
-                "name": name, "ph": "X", "ts": t0,
-                "dur": self._now_us() - t0,
-                "pid": 0, "tid": 0, "args": args,
-            })
-
-    def instant(self, name: str, **args) -> None:
-        self.events.append({"name": name, "ph": "i", "ts": self._now_us(),
-                            "s": "p", "pid": 0, "tid": 0, "args": args})
-
-    def clear(self) -> None:
-        self.events.clear()
-        self._t0 = time.perf_counter()
-
-    def to_chrome(self, path: Optional[str] = None) -> dict:
-        """The Chrome trace document; written to ``path`` when given."""
-        doc = {"traceEvents": list(self.events), "displayTimeUnit": "ms"}
-        if path is not None:
-            with open(path, "w") as f:
-                json.dump(doc, f)
-        return doc
-
-
-_TRACER = Tracer()
-
-
-def get_tracer() -> Tracer:
-    return _TRACER
+def named_phase(name: str):
+    """``jax.named_scope(name)``: the phase's name on the ``op_name`` of
+    every instruction traced inside it."""
+    return jax.named_scope(name)
 
 
 def host_span(name: str, **args):
-    """Tracer span while telemetry is enabled, else a free no-op. The
-    single guard the hot host paths (``engine.compiled``, ``run_grid``)
-    call — one dict lookup when off."""
+    """A profiler annotation (``args`` as its metadata) while host
+    instrumentation is on, else a free no-op. The single guard the hot
+    host paths (``engine.compiled``, ``run_grid``) call — one list
+    lookup when off."""
     if not _metrics.enabled():
         return _NULL
-    return _TRACER.span(name, **args)
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
-def host_instant(name: str, **args) -> None:
-    if _metrics.enabled():
-        _TRACER.instant(name, **args)
+#: the annotation of the collection under way (collections do not nest)
+_GC_OPEN: list = []
 
 
-def write_trace(path: str) -> dict:
-    """Write the accumulated host trace as Chrome-trace JSON."""
-    return _TRACER.to_chrome(path)
+def _gc_callback(phase: str, info: dict) -> None:
+    if phase == "start":
+        ann = jax.profiler.TraceAnnotation(
+            GC_SPAN, generation=info["generation"])
+        ann.__enter__()
+        _GC_OPEN.append(ann)
+    elif _GC_OPEN:
+        _GC_OPEN.pop().__exit__(None, None, None)
+
+
+def gc_spans(on: bool) -> None:
+    """Register (``on``) or remove the ``host.gc`` callback; the
+    host-instrumentation switch calls this."""
+    registered = _gc_callback in gc.callbacks
+    if on and not registered:
+        gc.callbacks.append(_gc_callback)
+    elif not on and registered:
+        gc.callbacks.remove(_gc_callback)

@@ -1,17 +1,28 @@
 """repro.obs telemetry layer (DESIGN.md §8).
 
-The two contracts under test:
+The contracts under test:
 
 * zero-overhead-off: with ``telemetry=False`` (the default) the built
-  programs are the exact seed programs — no ``debug_callback`` in the
-  jaxpr, identical compiled-loop cache keys, bit-identical histories;
+  programs hold no ``debug_callback`` in the jaxpr, identical
+  compiled-loop cache keys, bit-identical histories;
+* phase names: every program carries its phases in the compiled
+  ``op_name`` metadata, and nothing else in the program changes;
+* host plane: host spans, ``host.gc`` spans and ``--profile`` land in a
+  ``jax.profiler`` trace; the compile counter tallies JAX's events;
 * forensics-on: with ``telemetry=True`` a run under an attack yields the
   tap stream (per-iteration Δ₂ included), stacked ``grad_norm`` /
   ``rejected`` histories, and an ``aggregator_confusion`` tally whose
   precision/recall surface in ``Experiment.summary()``.
 """
+import contextlib
 import dataclasses
+import gc
+import glob
 import json
+import os
+import re
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -91,6 +102,54 @@ class TestZeroOverheadOff:
         env = make_env("cartpole(horizon=10)")
         run_decbyzpg(env, _cfg(telemetry=True, seed=7), 3)
         assert capsys.readouterr().out == ""
+
+
+#: every phase of the DecByzPG window program (DESIGN.md §8)
+WINDOW_PHASES = ("decbyzpg.estimate", "decbyzpg.rollout",
+                 "decbyzpg.gradient", "decbyzpg.aggregate",
+                 "decbyzpg.update", "decbyzpg.agree", "agree.round",
+                 "decbyzpg.diagnostics")
+
+
+def _window_text(cfg, rows=2, W=2, T=4) -> str:
+    """Compiled text of the lane window program (as the sweep service
+    builds it) for one scenario of ``cfg``; the engine's program cache
+    is cleared first, so the build traces the code as it is now."""
+    env = make_env("cartpole(horizon=10)")
+    engine.clear_cache()
+    (static_cfg, names), members = next(iter(
+        engine.lane_groups([("s", cfg)]).items()))
+    vals, seeds = engine.lane_operands(
+        members, jnp.arange(rows, dtype=jnp.int32), rows)
+    carry = engine.lane_init_loop(env, static_cfg, rows)(seeds)
+    win = engine.lane_window_loop(env, static_cfg, T, names, W, rows)
+    text = win.lower(carry, vals, seeds, np.arange(W)).compile().as_text()
+    engine.clear_cache()
+    return text
+
+
+def _instruction_lines(text: str) -> list:
+    """The HLO instruction lines with their metadata stripped."""
+    return [re.sub(r", metadata=\{[^}]*\}", "", line)
+            for line in text.splitlines() if " = " in line]
+
+
+class TestPhaseNames:
+    CFG = dict(attack="large_noise", aggregator="rfa", agreement="mda")
+
+    def test_window_program_names_every_phase(self):
+        text = _window_text(_cfg(**self.CFG))
+        op_names = "\n".join(re.findall(r'op_name="([^"]*)"', text))
+        missing = [p for p in WINDOW_PHASES if p not in op_names]
+        assert not missing, missing
+
+    def test_names_change_only_metadata(self, monkeypatch):
+        named = _window_text(_cfg(**self.CFG))
+        monkeypatch.setattr(obs, "named_phase",
+                            lambda name: contextlib.nullcontext())
+        plain = _window_text(_cfg(**self.CFG))
+        assert not re.search(r'op_name="[^"]*decbyzpg\.', plain)
+        assert _instruction_lines(named) == _instruction_lines(plain)
 
 
 class TestForensicsOn:
@@ -224,31 +283,100 @@ class TestHostPlane:
         events = [r["event"] for r in sink.records]
         assert events == ["miss", "hit"]
 
-    def test_tracer_chrome_format(self, tmp_path):
-        tr = obs.Tracer()
-        with tr.span("phase", n=3):
-            pass
-        tr.instant("marker")
-        doc = tr.to_chrome()
-        assert {e["ph"] for e in doc["traceEvents"]} == {"X", "i"}
-        x = next(e for e in doc["traceEvents"] if e["ph"] == "X")
-        assert x["name"] == "phase" and x["dur"] >= 0 \
-            and x["args"] == {"n": 3}
-        path = str(tmp_path / "trace.json")
-        tr.to_chrome(path)
-        assert json.load(open(path))["displayTimeUnit"] == "ms"
-
-    def test_host_span_noop_when_disabled(self):
-        tr = obs.get_tracer()
-        tr.clear()
-        with obs.host_span("nope"):
-            pass
-        assert tr.events == []
-        with obs.telemetry():
-            with obs.host_span("yes"):
+    def test_host_span_noop_when_disabled(self, tmp_path):
+        assert isinstance(obs.host_span("nope"), contextlib.nullcontext)
+        with jax.profiler.trace(str(tmp_path)):
+            with obs.host_span("test.nope"):
                 pass
-        assert [e["name"] for e in tr.events] == ["yes"]
-        tr.clear()
+            with obs.telemetry():
+                with obs.host_span("test.yes", n=3):
+                    pass
+        names = _host_event_names(tmp_path)
+        assert "test.yes" in names and "test.nope" not in names
+
+    def test_gc_spans_only_while_enabled(self, tmp_path):
+        with jax.profiler.trace(str(tmp_path / "off")):
+            gc.collect()
+        assert "host.gc" not in _host_event_names(tmp_path / "off")
+        with obs.telemetry(), jax.profiler.trace(str(tmp_path / "on")):
+            gc.collect()
+        assert "host.gc" in _host_event_names(tmp_path / "on")
+        assert not any(getattr(cb, "__module__", "") == "repro.obs.trace"
+                       for cb in gc.callbacks)
+
+    def test_compile_counter_sees_compile_then_cache_hit(self, tmp_path):
+        """In a fresh process with a persistent cache in ``tmp_path``: the
+        first call compiles, the second (in-memory caches cleared) loads
+        the executable from the cache. The traces of the inner jitted
+        function count, but their seconds lie inside the outer trace's,
+        so the seconds add up to no more than the call took."""
+        code = (
+            "import json, time, jax, jax.numpy as jnp\n"
+            "from repro import obs\n"
+            "for k, v in (('jax_compilation_cache_dir', %r),\n"
+            "             ('jax_persistent_cache_min_compile_time_secs', 0),\n"
+            "             ('jax_persistent_cache_min_entry_size_bytes', 0)):\n"
+            "    jax.config.update(k, v)\n"
+            "c = obs.compile_counter()\n"
+            "g = jax.jit(lambda x: jnp.tanh(x @ x))\n"
+            "f = lambda x: g(g(g(x))).sum()\n"
+            "x = jax.device_put(1.0 + jnp.arange(4096.0).reshape(64, 64))\n"
+            "s0, t0 = c.snapshot(), time.time()\n"
+            "jax.jit(f)(x).block_until_ready()\n"
+            "s1, wall = c.snapshot(), time.time() - t0\n"
+            "jax.clear_caches()\n"
+            "jax.jit(f)(x).block_until_ready()\n"
+            "s2 = c.snapshot()\n"
+            "print(json.dumps([vars(s1 - s0), vars(s2 - s1), wall]))\n"
+        ) % str(tmp_path / "cache")
+        first, second, wall = json.loads(
+            _run_python(code).splitlines()[-1])
+        assert first["backend_compiles"] == 1 and first["cache_hits"] == 0
+        assert first["traces"] >= 2 and first["lowerings"] == 1
+        assert first["trace_s"] + first["lower_s"] + first["compile_s"] \
+            <= wall
+        assert second["backend_compiles"] == 1 and second["cache_hits"] == 1
+        for d in (first, second):
+            assert d["trace_s"] > 0 and d["lower_s"] > 0 \
+                and d["compile_s"] > 0
+
+    def test_profile_flag_writes_a_profiler_trace(self, tmp_path):
+        out = tmp_path / "run"
+        _run_python(
+            "from repro.launch import train\n"
+            "train.main()\n",
+            "--arch", "llama3.2-1b", "--reduced", "--agents", "2",
+            "--steps", "2", "--window", "2", "--seq", "16", "--batch", "1",
+            "--telemetry-out", str(out), "--profile",
+            JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+        assert glob.glob(str(out / "**" / "*.xplane.pb"), recursive=True)
+        assert "train.window" in _host_event_names(out)
+        assert (out / "manifest.json").is_file()
+
+
+def _host_event_names(trace_dir) -> set:
+    """Names of the events on the host planes of the newest profiler
+    trace under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(str(trace_dir), "**",
+                                         "*.xplane.pb"), recursive=True))[-1]
+    return {e.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+
+
+def _run_python(code: str, *args, **env_extra) -> str:
+    """Run ``code`` in a fresh CPU process with ``src`` on the path;
+    returns its standard output."""
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", **env_extra,
+               PYTHONPATH=os.pathsep.join(
+                   [src] + [p for p in [os.environ.get("PYTHONPATH")] if p]))
+    proc = subprocess.run([sys.executable, "-c", code, *args], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    return proc.stdout
 
 
 class TestDispatchCounters:

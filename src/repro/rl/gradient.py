@@ -15,10 +15,19 @@ from repro.rl.rollout import Trajectory
 def step_log_probs(params, traj: Trajectory, activation="tanh"):
     """(H,) log π_θ(a_h | s_h), masked. ``activation`` is a policy logits
     spec — an MLP activation string or a callable (params, obs) ->
-    logits."""
+    logits.
+
+    The taken action's entry is selected by compare-and-sum, never by
+    ``take_along_axis``: under the fleet's vmaps XLA lowers that to an
+    element-by-element gather, which took most of a TPU fleet
+    iteration. The select fuses into the log-softmax, gives the same
+    values and gradients bit for bit, and, unlike a one-hot product,
+    keeps a ``-inf`` on an action not taken out of the sum."""
     logits = policy_logits(params, traj.obs, activation)    # (H, A)
     lp = jax.nn.log_softmax(logits)
-    lp = jnp.take_along_axis(lp, traj.actions[..., None], axis=-1)[..., 0]
+    iota = jax.lax.broadcasted_iota(jnp.int32, lp.shape, lp.ndim - 1)
+    lp = jnp.sum(jnp.where(iota == traj.actions[..., None], lp, 0.0),
+                 axis=-1)
     return lp * traj.mask
 
 

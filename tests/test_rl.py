@@ -1,14 +1,18 @@
 """RL substrate tests: env invariants, rollout masking, PG estimator
-correctness vs finite differences, importance-weight unbiasedness."""
+correctness vs finite differences, importance-weight unbiasedness, and the
+gather-free action log-probability select."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from repro.rl import gradient
 from repro.rl.envs import make_cartpole, make_lunarlander
 from repro.rl.gradient import (grad_estimate, importance_weights,
                                step_log_probs, weighted_grad_estimate)
-from repro.rl.policy import init_mlp, mlp_logits
-from repro.rl.rollout import batch_return, sample_batch, sample_trajectory
+from repro.rl.policy import init_mlp, mlp_logits, policy_logits
+from repro.rl.rollout import (Trajectory, batch_return, sample_batch,
+                              sample_trajectory)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -139,3 +143,94 @@ def test_self_normalized_is_identity_at_equal_policies():
         for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(g_is)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-5, atol=1e-6)
+
+
+def _gather_step_log_probs(params, traj, activation="tanh"):
+    """The take_along_axis form that step_log_probs replaced."""
+    logits = policy_logits(params, traj.obs, activation)
+    lp = jax.nn.log_softmax(logits)
+    lp = jnp.take_along_axis(lp, traj.actions[..., None], axis=-1)[..., 0]
+    return lp * traj.mask
+
+
+def _assert_trees_equal(a, b):
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b), strict=True):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def _estimates():
+    """The fleet's two estimates, as one agent runs them per iteration,
+    plus the self-normalized IS form. A new function on every call, so
+    that no trace is reused across a swap of ``step_log_probs``."""
+    def estimates(params, prev, traj, w, w_small):
+        return (grad_estimate(params, traj, 0.99, activation="relu",
+                              sample_weights=w),
+                weighted_grad_estimate(prev, params, traj, 0.99,
+                                       activation="relu",
+                                       sample_weights=w_small),
+                weighted_grad_estimate(prev, params, traj, 0.99,
+                                       activation="relu",
+                                       sample_weights=w_small,
+                                       self_normalized=True))
+    return estimates
+
+
+@pytest.mark.parametrize("make_env", (make_cartpole, make_lunarlander),
+                         ids=("cartpole-A2", "lunarlander-A4"))
+def test_select_matches_gather_bitwise(make_env, monkeypatch):
+    """The compare-and-sum select gives the gather's log-probabilities
+    and gradients bit for bit, masked steps included, and stays finite
+    where an action not taken has probability 0."""
+    env = make_env(horizon=100)
+    params = init_mlp(KEY, (env.obs_dim, 16, 16, env.n_actions))
+    prev = jax.tree.map(lambda p: p * 0.97, params)
+    traj = sample_batch(env, params, KEY, 20, activation="relu")
+    assert 0 < float(jnp.mean(traj.mask)) < 1     # masked steps present
+    for t in (traj, jax.tree.map(lambda x: x[0], traj)):
+        np.testing.assert_array_equal(
+            np.asarray(step_log_probs(params, t, "relu")),
+            np.asarray(_gather_step_log_probs(params, t, "relu")))
+
+    def blocked(p, obs):
+        # -inf on every action but the one taken
+        logits = mlp_logits(p, obs, "relu")
+        iota = jnp.arange(env.n_actions)
+        return jnp.where(iota == traj.actions[0][:, None], logits, -jnp.inf)
+    lp = step_log_probs(params, jax.tree.map(lambda x: x[0], traj), blocked)
+    assert bool(jnp.all(jnp.isfinite(lp)))
+    np.testing.assert_array_equal(np.asarray(lp), 0.0)
+
+    w = jnp.full(20, 1 / 20)
+    w_small = jnp.where(jnp.arange(20) < 4, 1 / 4, 0.0)
+    new = jax.jit(_estimates())(params, prev, traj, w, w_small)
+    monkeypatch.setattr(gradient, "step_log_probs", _gather_step_log_probs)
+    old = jax.jit(_estimates())(params, prev, traj, w, w_small)
+    _assert_trees_equal(new, old)
+
+
+def _primitives(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if hasattr(sub, "eqns"):
+                    yield from _primitives(sub)
+
+
+def test_fleet_estimate_has_no_gather():
+    """At the paper fleet's shape (6 rows x 13 agents x 20 episodes x 100
+    steps) the vmapped estimates select without gather or scatter."""
+    rows, K, M, H = 6, 13, 20, 100
+    params = init_mlp(KEY, (4, 16, 16, 2))
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct((rows, K) + shape, dtype)
+    theta = jax.tree.map(lambda p: sds(p.shape), params)
+    traj = Trajectory(sds((M, H, 4)), sds((M, H), jnp.int32), sds((M, H)),
+                      sds((M, H)))
+    w = jax.ShapeDtypeStruct((M,), jnp.float32)
+    fn = jax.vmap(jax.vmap(_estimates(), in_axes=(0, 0, 0, None, None)),
+                  in_axes=(0, 0, 0, None, None))
+    prims = set(_primitives(jax.make_jaxpr(fn)(theta, theta, traj, w, w)))
+    assert "dot_general" in prims            # the walk reaches the MLP
+    assert not prims & {"gather", "scatter-add", "scatter"}

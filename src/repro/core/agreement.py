@@ -53,6 +53,24 @@ def _subsets(K: int, size: int) -> np.ndarray:
                     dtype=np.int32)
 
 
+def subset_diameters(d2: jnp.ndarray, n_keep: int) -> jnp.ndarray:
+    """(n, n) squared distances -> (n_sub,) squared diameter of each
+    ``n_keep``-subset, in :func:`_subsets` order.
+
+    A masked max over a trace-time membership table ``pair[i, j, s] =
+    i, j ∈ S_s``, subsets in the minor axis: no gather of scalars, which
+    under the fleet's vmaps runs element by element. Masked entries read
+    ``-inf``, so every member pair, the diagonal included, counts exactly
+    as it would in a max over the subset's own entries.
+    """
+    n = d2.shape[0]
+    subs = _subsets(n, n_keep)
+    member = np.zeros((n, subs.shape[0]), bool)
+    member[subs, np.arange(subs.shape[0])[:, None]] = True
+    pair = member[:, None, :] & member[None, :, :]       # (n, n, n_sub)
+    return jnp.max(jnp.where(pair, d2[:, :, None], -jnp.inf), axis=(0, 1))
+
+
 def mda_mean(received: jnp.ndarray, n_keep: int) -> jnp.ndarray:
     """Exact Minimum-Diameter Averaging: received (n, d) -> (d,).
 
@@ -69,11 +87,7 @@ def mda_mean(received: jnp.ndarray, n_keep: int) -> jnp.ndarray:
             f"{MDA_MAX_AGENTS}; use method='gda' or a sparser topology "
             f"(the limit applies to the neighborhood size, not K)")
     subs = jnp.asarray(_subsets(K, n_keep))              # (n_sub, n_keep)
-    d2 = pairwise_sq_dists(received)
-    # diameter of each subset = max pairwise distance within it
-    sub_d = d2[subs[:, :, None], subs[:, None, :]]       # (n_sub, nk, nk)
-    diam = jnp.max(sub_d.reshape(subs.shape[0], -1), axis=1)
-    best = jnp.argmin(diam)
+    best = jnp.argmin(subset_diameters(pairwise_sq_dists(received), n_keep))
     return jnp.mean(received[subs[best]], axis=0)
 
 
